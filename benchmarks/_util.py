@@ -1,4 +1,4 @@
-"""Benchmark helpers: wall timing + subprocess runs with N virtual devices."""
+"""Benchmark helpers: wall timing + CPU subprocess runs with N virtual devices."""
 from __future__ import annotations
 
 import os
@@ -29,11 +29,15 @@ def timed(fn, *args, repeats: int = 3, warmup: int = 1, **kw):
 def run_with_devices(script: str, num_devices: int, timeout: int = 1200) -> str:
     """Run a python snippet under N virtual CPU devices; return stdout.
 
-    Used for par(1)/par(2) measurements (the paper's 'available processors'
-    column) — jax fixes the device count at first init, so a fresh process
-    is the only way to vary it.
+    A CPU rehearsal, never a device measurement: the child is pinned to
+    ``JAX_PLATFORMS=cpu``.  Used for par(1)/par(2) runs (the paper's
+    'available processors' column) — jax fixes the device count at first
+    init, so a fresh process is the only way to vary it.  The parent
+    imports JAX, and a chip belongs to one process at a time, so a child
+    must never ask for the accelerator.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
     env["PYTHONPATH"] = SRC
     proc = subprocess.run(

@@ -35,7 +35,6 @@ SWEEP = [
 
 SCRIPT = """
 import time, jax, jax.numpy as jnp
-from repro import compat
 from repro.core import StreamProgram, FutureEvaluator, evaluate
 M, D, ROWS = {micro}, {dim}, {rows}
 CELLS = 16  # 4 virtual stages x 4 cells, identical for every layout
@@ -45,7 +44,7 @@ prog = StreamProgram(lambda w, x: (w, jnp.tanh(x @ w)), W, CELLS,
 items = jax.random.normal(jax.random.PRNGKey(1), (M, ROWS // M, D))
 runs = {{}}
 for name, ndev, v in {sweep!r}:
-    mesh = compat.make_mesh((ndev,), ("pod",), devices=jax.devices()[:ndev])
+    mesh = jax.make_mesh((ndev,), ("pod",), devices=jax.devices()[:ndev])
     ev = FutureEvaluator(mesh, "pod", schedule=name, interleave=v)
     fn = jax.jit(lambda items, ev=ev: evaluate(prog, items, ev)[1])
     jax.block_until_ready(fn(items))  # compile

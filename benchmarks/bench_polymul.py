@@ -15,15 +15,14 @@ from repro.algorithms import polynomial as poly
 
 PAR_SCRIPT = """
 import time, jax
-from repro import compat
 from repro.algorithms import polynomial as poly
 from repro.core.stream import FutureEvaluator
 power, limbs, big, tpc, xch, acc = {power}, {limbs}, {big}, {tpc}, {xch}, {acc}
 cap = {cap}
 x = poly.fateman_poly(power, cap, limbs, big_factor=big)
 y = poly.fateman_poly(power, cap, limbs, big_factor=big)
-mesh = compat.make_mesh((jax.device_count(),), ("pod",),
-                        axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((jax.device_count(),), ("pod",),
+                        axis_types=(jax.sharding.AxisType.Auto,))
 ev = FutureEvaluator(mesh, "pod")
 fn = jax.jit(lambda x, y: poly.times(x, y, evaluator=ev, num_x_chunks=xch,
                                      terms_per_cell=tpc, acc_capacity=acc))

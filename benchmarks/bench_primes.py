@@ -14,12 +14,11 @@ from repro.algorithms import sieve
 
 PAR_SCRIPT = """
 import time, numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.algorithms import sieve
 from repro.core.stream import FutureEvaluator
 limit, block, ppc, cells = {limit}, {block}, {ppc}, {cells}
-mesh = compat.make_mesh((jax.device_count(),), ("pod",),
-                        axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((jax.device_count(),), ("pod",),
+                        axis_types=(jax.sharding.AxisType.Auto,))
 ev = FutureEvaluator(mesh, "pod")
 run = jax.jit(lambda items_unused: 0)  # warm placeholder
 p, c = sieve.run_sieve(limit, block_size=block, primes_per_cell=ppc,

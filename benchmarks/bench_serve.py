@@ -79,7 +79,6 @@ CPU_HBM_BPS = 2e10
 
 SCRIPT = """
 import json, time, jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs.base import DecodePipelineConfig
 from repro.configs.registry import get_config, smoke_config
 from repro.models import transformer as T
@@ -94,7 +93,7 @@ if DIM:
                              head_dim=DIM // 8, num_kv_heads=2,
                              vocab_size=2048)
 params = init_params(jax.random.PRNGKey(0), T.model_layout(cfg))
-mesh = compat.make_mesh((2,), ("pod",), devices=jax.devices()[:2])
+mesh = jax.make_mesh((2,), ("pod",), devices=jax.devices()[:2])
 scfg = ServeConfig(max_batch=BATCH, max_len=64, prefill_chunk=CHUNK,
                    max_new_tokens=MAX_NEW)
 

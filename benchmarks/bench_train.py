@@ -31,7 +31,6 @@ BACKWARDS = ("autodiff", "planned")
 
 SCRIPT = """
 import time, jax, jax.numpy as jnp
-from repro import compat
 from repro.core import StreamProgram, FutureEvaluator, evaluate
 M, D, ROWS = {micro}, {dim}, {rows}
 CELLS = 16
@@ -43,7 +42,7 @@ def loss(W, items, ev):
     return jnp.sum(evaluate(prog, items, ev)[1] ** 2)
 runs = {{}}
 for name, ndev, v in {sweep!r}:
-    mesh = compat.make_mesh((ndev,), ("pod",), devices=jax.devices()[:ndev])
+    mesh = jax.make_mesh((ndev,), ("pod",), devices=jax.devices()[:ndev])
     for bwd in {backwards!r}:
         ev = FutureEvaluator(mesh, "pod", schedule=name, interleave=v,
                              backward=bwd)

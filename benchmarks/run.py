@@ -40,6 +40,7 @@ from benchmarks import (
     bench_serve,
     bench_train,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "primes": bench_primes,      # Table 1 / Fig 3
@@ -382,6 +383,7 @@ def main() -> None:
         help="relative slowdown tolerated per sweep cell (default 0.10)",
     )
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.check:
         raise SystemExit(

@@ -14,7 +14,6 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
 from repro.algorithms import polynomial as poly
 from repro.core import FutureEvaluator
 
@@ -62,9 +61,9 @@ def main():
         assert poly.to_dict(out) == ref, "stream/lazy result mismatch"
 
         if jax.device_count() >= 2:
-            mesh = compat.make_mesh(
+            mesh = jax.make_mesh(
                 (jax.device_count(),), ("pod",),
-                axis_types=(compat.AxisType.Auto,),
+                axis_types=(jax.sharding.AxisType.Auto,),
             )
             fut = FutureEvaluator(mesh, "pod")
             jit_par = jax.jit(

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import (
     FutureEvaluator,
     LazyEvaluator,
@@ -49,9 +48,9 @@ def main():
     print("lazy:   outs[0] =", np.asarray(lazy.items[0]))
 
     if jax.device_count() >= 2 and num_cells % jax.device_count() == 0:
-        mesh = compat.make_mesh(
+        mesh = jax.make_mesh(
             (jax.device_count(),), ("pod",),
-            axis_types=(compat.AxisType.Auto,),
+            axis_types=(jax.sharding.AxisType.Auto,),
         )
         fut = program.collect(FutureEvaluator(mesh, "pod"))
         print("future: outs[0] =", np.asarray(fut.items[0]))

@@ -70,7 +70,7 @@ class ArchConfig:
     # per-op implementation dispatch (repro.kernels.get_impl): "xla" runs
     # the pure-jnp paths, "pallas" the fused kernels (interpret-emulated
     # off-TPU), "auto" picks pallas on TPU and xla elsewhere.
-    kernels: str = "xla"
+    kernels: str = "auto"
     # sub-quadratic attention available => long_500k applicable
     notes: str = ""
 

@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 PyTree = Any
 
 
@@ -65,9 +63,7 @@ class Future:
         leaves, treedef = jax.tree.flatten(self._value)
         anchor_leaf = jax.tree.leaves(anchor)[0]
         # Barrier couples (value, anchor) so neither crosses the other.
-        # (compat: 0.4.x lax.optimization_barrier has no AD rule; the
-        # shim adds one so grad-through-pipeline works everywhere.)
-        barriered = compat.optimization_barrier(tuple(leaves) + (anchor_leaf,))
+        barriered = lax.optimization_barrier(tuple(leaves) + (anchor_leaf,))
         self._forced = True
         return jax.tree.unflatten(treedef, list(barriered[: len(leaves)]))
 

@@ -273,7 +273,7 @@ def peak_inflight_items(
     most ``min(S, M)``; interleaved holds ``V * min(S, M)``.  These
     closed forms are exact against the combined plans' stash/release
     columns (tested over the grid).  ``backward="autodiff"`` is the
-    degraded truth of letting ``jax.grad`` transpose the forward scan:
+    weaker truth of letting ``jax.grad`` transpose the forward scan:
     the fwd/bwd phase boundary keeps **all** ``V*M`` unit inputs live
     regardless of schedule name — before the planned backward existed,
     1F1B's ``min(S, M)`` was a modeling assumption the execution never

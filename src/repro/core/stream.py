@@ -90,7 +90,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.core import graph as G
 from repro.core.future import ppermute_future
 from repro.core.graph import Stream, StreamResult
@@ -103,6 +102,13 @@ from repro.core.schedules import (
 
 PyTree = Any
 CellFn = Callable[[PyTree, PyTree], tuple[PyTree, PyTree]]
+
+# The pipeline regions run without JAX's varying-manual-axes type check.
+# Under it, the Pallas interpreter that runs the fused serving kernels
+# off-TPU cannot evaluate a kernel (its block slicing indexes varying
+# operands with the invariant grid index), and every zero-initialized
+# loop carry would need a cast to varying.
+_CHECK_VMA = False
 
 
 # ---------------------------------------------------------------------------
@@ -554,37 +560,22 @@ class FutureEvaluator:
         }
 
         def pipelined(stage_ids, local_states, local_consts, local_feeds):
-            # Stage index arrives as a stage-sharded input rather than
-            # lax.axis_index: the latter lowers to PartitionId, which the
-            # 0.4.x SPMD partitioner rejects inside partial-manual regions.
             stage = stage_ids[0]
             local_feeds = [
                 jax.tree.map(lambda x: x[0], f) for f in local_feeds
             ]  # each (J, ...)
-            # The loop carry varies per-device; mark it so (vma JAX).
-            def _varying(x):
-                return compat.pcast(x, (axis,), to="varying")
-
-            feed_shapes = [
-                jax.tree.map(lambda x: x[0], f) for f in local_feeds
-            ]
             feed0 = [
-                jax.tree.map(lambda x: _varying(jnp.zeros_like(x)), fs)
-                for fs in feed_shapes
+                jax.tree.map(lambda x: jnp.zeros_like(x[0]), f)
+                for f in local_feeds
             ]
-            item_shape = jax.tree.map(
+            zero_item = jax.tree.map(
                 lambda x: jnp.zeros(x.shape, x.dtype), flow_shape
             )
-            zero_item = jax.tree.map(
-                lambda x: _varying(jnp.zeros_like(x)), item_shape
-            )
             buf0 = jax.tree.map(
-                lambda x: _varying(jnp.zeros((k_,) + x.shape, x.dtype)),
-                item_shape,
+                lambda x: jnp.zeros((k_,) + x.shape, x.dtype), flow_shape
             )
             outs0 = jax.tree.map(
-                lambda x: _varying(jnp.zeros((m_,) + x.shape, x.dtype)),
-                item_shape,
+                lambda x: jnp.zeros((m_,) + x.shape, x.dtype), flow_shape
             )
             if v_ > 1:
                 local_states = jax.tree.map(
@@ -788,7 +779,7 @@ class FutureEvaluator:
                 )
             return local_states, outs
 
-        pipelined = compat.shard_map(
+        pipelined = jax.shard_map(
             pipelined,
             mesh=self.mesh,
             in_specs=(
@@ -799,12 +790,21 @@ class FutureEvaluator:
             ),
             out_specs=(spec_shard(init_state), spec_shard(flow_shape)),
             axis_names={axis},
+            check_vma=_CHECK_VMA,
         )
         final_states, outs = pipelined(
             jnp.arange(d_, dtype=jnp.int32), init_state, const_state, feeds_fed
         )
         if v_ > 1:
-            final_states = jax.tree.map(lambda x: x[inv_perm], final_states)
+            # Back to chain order, still split over the stages (left to
+            # itself the gather replicates the state on every device).
+            staged = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec(axis)
+            )
+            final_states = jax.tree.map(
+                lambda x: lax.with_sharding_constraint(x[inv_perm], staged),
+                final_states,
+            )
         # outs is stage-sharded (D*M, ...); only the last stage's block is
         # real.  One static slice at the boundary — no psum, no all-reduce.
         outs = jax.tree.map(
@@ -964,12 +964,9 @@ class FutureEvaluator:
 
         xs_f, xs_b = _plan_xs(plan), _plan_xs(bplan)
 
-        def _varying(x):
-            return compat.pcast(x, (axis,), to="varying")
-
         def _zeros(shape_prefix, struct):
             return jax.tree.map(
-                lambda s: _varying(jnp.zeros(shape_prefix + s.shape, s.dtype)),
+                lambda s: jnp.zeros(shape_prefix + s.shape, s.dtype),
                 struct,
             )
 
@@ -1100,7 +1097,7 @@ class FutureEvaluator:
                 if with_stash
                 else spec_shard(item_struct)
             )
-            region = compat.shard_map(
+            region = jax.shard_map(
                 forward_region,
                 mesh=self.mesh,
                 in_specs=(
@@ -1110,6 +1107,7 @@ class FutureEvaluator:
                 ),
                 out_specs=out_specs,
                 axis_names={axis},
+                check_vma=_CHECK_VMA,
             )
 
             def forward(state0, src_items):
@@ -1275,7 +1273,7 @@ class FutureEvaluator:
             jax.ShapeDtypeStruct(state_leaves[i].shape, state_leaves[i].dtype)
             for i in diff_ids
         )
-        backward_region = compat.shard_map(
+        backward_region = jax.shard_map(
             backward_region,
             mesh=self.mesh,
             in_specs=(
@@ -1287,6 +1285,7 @@ class FutureEvaluator:
             ),
             out_specs=(spec_shard(diff_struct), spec_shard(item_struct)),
             axis_names={axis},
+            check_vma=_CHECK_VMA,
         )
 
         def _backward(state0, stash, d_final_diff, d_outs):

@@ -15,10 +15,12 @@ oracle the kernel is tested bitwise/tolerance against):
 
 Model code selects implementations through :func:`get_impl` driven by the
 ``kernels`` config knob (``"xla" | "pallas" | "auto"``) instead of
-hard-coding XLA.  ``"auto"`` resolves to ``"pallas"`` on TPU and
-``"xla"`` elsewhere; an explicit ``"pallas"`` off-TPU runs the kernels
-under the Pallas interpreter (bit-accurate kernel logic, no Mosaic) —
+hard-coding XLA.  ``"auto"`` (the default) resolves to ``"pallas"`` on
+TPU and ``"xla"`` elsewhere; an explicit ``"pallas"`` off-TPU runs the
+kernels under the Pallas interpreter (kernel logic without Mosaic) —
 that is what keeps the tier-1 parity batteries runnable on CPU.
+Interpret mode exists for those tests only: on a TPU the kernels always
+compile through Mosaic, and a kernel that fails there raises.
 """
 from __future__ import annotations
 
@@ -67,7 +69,7 @@ def default_interpret() -> bool:
 def resolve_mode(mode: str | None) -> str:
     """Validate the ``kernels`` knob and collapse ``auto`` to a backend."""
     if mode is None:
-        mode = "xla"
+        mode = "auto"
     if mode not in KERNEL_MODES:
         raise ValueError(
             f"kernels={mode!r}; expected one of {KERNEL_MODES}"

@@ -12,7 +12,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import default_interpret
-from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.decode_attention.kernel import (
+    DEFAULT_BLOCK_S,
+    decode_attention_pallas,
+)
 
 FUSION_SCOPE = "pallas_decode_attention"
 
@@ -27,10 +30,11 @@ def fused_decode_attention(
     pos: jnp.ndarray,      # (B,) int32 write positions
     kv_len: jnp.ndarray,   # (B,) or (B, 1) valid KV count after the write
     softmax_scale: float | None = None,
+    block_s: int = DEFAULT_BLOCK_S,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Drop-in for the slab-update + attention_dense decode path; returns
-    the attention context ``(B, 1, H, dh)`` (bitwise equal to ref.py)."""
+    the attention context ``(B, 1, H, dh)`` (ref.py to fp32 rounding)."""
     if interpret is None:
         interpret = default_interpret()
     b = q.shape[0]
@@ -41,6 +45,7 @@ def fused_decode_attention(
             jnp.asarray(pos).reshape(b),
             jnp.asarray(kv_len).reshape(b),
             softmax_scale=softmax_scale,
+            block_s=block_s,
             interpret=interpret,
         )
     return out[:, None]

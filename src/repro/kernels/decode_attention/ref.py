@@ -4,9 +4,10 @@ This is the exact op sequence ``repro.models.transformer._self_attn``
 runs on the decode (S==1) path: functionally update the K/V slab at each
 sequence's write position (``.at[idx, pos].set`` — the HBM slab copy the
 fused kernel removes), then dense attention over the updated slab with
-the ``kv_len`` prefix mask.  The kernel is gated on being bitwise equal
-to this function; this function stays bitwise equal to the model path by
-calling the same :func:`repro.models.layers.attention_dense`.
+the ``kv_len`` prefix mask.  The kernel is gated on matching this
+function (bitwise in bf16 while the cache is one block, to one bf16 ulp
+when it walks several); this function stays bitwise equal to the model
+path by calling the same :func:`repro.models.layers.attention_dense`.
 """
 from __future__ import annotations
 

@@ -8,7 +8,10 @@ the (tiny, B×d) normalization per tile in VMEM, so each weight tile is
 read once and the hidden state never materializes a normalized copy in
 HBM.  The per-tile recompute is bitwise-stable: every logit is an
 independent d-length dot, so vocab tiling cannot change its reduction
-order — outputs are bitwise equal to the unfused path (ref.py).
+order.  The dot accumulates in fp32 (the MXU's accumulator) and rounds
+once to the input dtype, as the unfused bf16 ``einsum`` in
+``layers.logits`` does — outputs are bitwise equal to the unfused path
+(ref.py) under the interpreter.
 
 Supports both norms the configs use (rmsnorm and OLMo's non-parametric
 layernorm) and both head layouts (untied ``(d, V)`` / tied embedding
@@ -36,22 +39,23 @@ def _normalize(x_ref, scale_ref, *, norm: str, eps: float):
     return xn.astype(x_ref.dtype)
 
 
-# The dot's output stays in the input dtype (as in ``layers.logits``) and
-# the fp32 upcast happens OUTSIDE the pallas_call: chaining the upcast
-# directly onto the in-kernel dot lets XLA's float-normalization cleanup
-# elide the low-precision rounding of the dot output, silently breaking
-# bitwise parity with the unfused path for bf16 models.
+# The output block stays in the input dtype (as ``layers.logits``'s dot
+# output does) and the fp32 upcast happens OUTSIDE the pallas_call: the
+# store is the one rounding of the fp32 accumulator, so XLA's
+# float-normalization cleanup cannot elide it.
+
+def _head_dot(xn, w_ref, o_ref, tied):
+    eq = "bd,vd->bv" if tied else "bd,dv->bv"
+    acc = jnp.einsum(eq, xn, w_ref[...], preferred_element_type=jnp.float32)
+    o_ref[...] = acc.astype(o_ref.dtype)
+
 
 def _emit_kernel_scaled(x_ref, scale_ref, w_ref, o_ref, *, norm, eps, tied):
-    xn = _normalize(x_ref, scale_ref, norm=norm, eps=eps)
-    eq = "bd,vd->bv" if tied else "bd,dv->bv"
-    o_ref[...] = jnp.einsum(eq, xn, w_ref[...])
+    _head_dot(_normalize(x_ref, scale_ref, norm=norm, eps=eps), w_ref, o_ref, tied)
 
 
 def _emit_kernel_plain(x_ref, w_ref, o_ref, *, norm, eps, tied):
-    xn = _normalize(x_ref, None, norm=norm, eps=eps)
-    eq = "bd,vd->bv" if tied else "bd,dv->bv"
-    o_ref[...] = jnp.einsum(eq, xn, w_ref[...])
+    _head_dot(_normalize(x_ref, None, norm=norm, eps=eps), w_ref, o_ref, tied)
 
 
 @functools.partial(
@@ -82,7 +86,7 @@ def emit_norm_logits_pallas(
         else pl.BlockSpec((d, block_v), lambda j: (0, j))
     )
     o_spec = pl.BlockSpec((b, block_v), lambda j: (0, j))
-    out_shape = jax.ShapeDtypeStruct((b, v), x.dtype)
+    out_shape = jax.ShapeDtypeStruct((b, v), x.dtype, vma=jax.typeof(x).vma)
     if norm == "rmsnorm":
         out = pl.pallas_call(
             functools.partial(

@@ -30,7 +30,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import SHAPES
 from repro.configs.registry import all_cells, get_config
 from repro.launch import specs as SP
@@ -107,7 +106,7 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str):
                             is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
     scale = 1
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             step = make_train_step(cfg, tcfg, ocfg, param_pspecs=pspecs)
             a_params, a_opt = SP.abstract_model_state(cfg, ocfg, rules, mesh)

@@ -15,20 +15,19 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
-        shape, axes, axis_types=(compat.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 
 def make_host_mesh(axis_name: str = "pod") -> jax.sharding.Mesh:
     """All local devices on one axis (CPU tests / examples)."""
-    return compat.make_mesh(
+    return jax.make_mesh(
         (jax.device_count(),), (axis_name,),
-        axis_types=(compat.AxisType.Auto,),
+        axis_types=(jax.sharding.AxisType.Auto,),
     )

@@ -20,11 +20,6 @@ same roofline artifacts as the baseline DP-over-pod mode for comparison.
 
     PYTHONPATH=src python -m repro.launch.pipeline_demo
 
-NB toolchain: the partial-manual (pod=manual, data/model=auto) region of
-a full transformer trips a hard CHECK (`sharding.IsManualSubgroup()`) in
-XLA <= 0.4.37's SPMD partitioner — this dry-run needs the newer jaxlib
-the seed targeted.  Single-axis (fully manual) pipelines, i.e. every
-tier-1 path, compile fine on either toolchain via repro.compat.
 """
 import json
 import time
@@ -33,7 +28,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import SHAPES
 from repro.configs.registry import get_config
 from repro.core.pipeline import pipeline_apply
@@ -160,9 +154,9 @@ def make_pipelined_loss(cfg, mesh):
 
 def main():
     if _SMALL:
-        mesh = compat.make_mesh(
+        mesh = jax.make_mesh(
             (2, 2, 2), ("pod", "data", "model"),
-            axis_types=(compat.AxisType.Auto,) * 3,
+            axis_types=(jax.sharding.AxisType.Auto,) * 3,
         )
     else:
         mesh = make_production_mesh(multi_pod=True)
@@ -184,7 +178,7 @@ def main():
 
     step = make_pipelined_loss(cfg, mesh)
     t0 = time.perf_counter()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(step, donate_argnums=(0,)).lower(a_params, a_batch)
         compiled = lowered.compile()
     compile_s = time.perf_counter() - t0

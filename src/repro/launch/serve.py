@@ -27,9 +27,9 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import DecodePipelineConfig
 from repro.configs.registry import ARCH_IDS, get_config, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.models.params import init_params, param_count
 from repro.serve.engine import Engine, ServeConfig, StreamEngine
@@ -61,11 +61,11 @@ def main(argv=None):
                     help="decode steps per device-program invocation")
     ap.add_argument("--admit-per-round", type=int, default=4)
     ap.add_argument("--kernels", choices=("xla", "pallas", "auto"),
-                    default="xla",
+                    default="auto",
                     help="decode-path kernel dispatch (repro.kernels): "
                     "pallas = fused decode-attention + emit epilogue "
-                    "(interpret-emulated off-TPU, bitwise equal); auto = "
-                    "pallas on TPU, xla elsewhere")
+                    "(interpret-emulated off-TPU); auto = pallas on TPU, "
+                    "xla elsewhere")
     ap.add_argument("--devices", type=int, default=0,
                     help="pipeline devices for --engine stream "
                     "(0 = all; 1 = LazyEvaluator, layer-sequential)")
@@ -106,6 +106,7 @@ def main(argv=None):
                     "raise@K, nan@K, wedge@K, or sigterm@K (implies the "
                     "supervisor; see repro.serve.supervisor)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -130,7 +131,7 @@ def main(argv=None):
         ndev = args.devices or jax.device_count()
         mesh = None
         if ndev > 1:
-            mesh = compat.make_mesh(
+            mesh = jax.make_mesh(
                 (ndev,), ("pod",), devices=jax.devices()[:ndev]
             )
         pcfg = DecodePipelineConfig(

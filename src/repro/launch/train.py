@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, smoke_config
 from repro.data.pipeline import DataConfig, PrefetchIterator, make_source
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.models.params import init_params, param_count
 from repro.parallel import sharding as SH
@@ -90,6 +91,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg, tcfg, ocfg = build(args)
     layout = T.model_layout(cfg)

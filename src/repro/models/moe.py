@@ -20,7 +20,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.models.params import ParamSpec
 
@@ -61,8 +60,8 @@ def _data_shards(t: int) -> int:
     whereas a flat cross-shard scatter triggers pathological resharding
     (observed: moonshot train_4k failed HLO verification at 256 chips).
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1
     shards = 1
     for ax in ("pod", "data"):

@@ -467,7 +467,8 @@ def _emit_logits(params, cfg: ArchConfig, x, kernels: str = "xla"):
 
     Under ``kernels="pallas"`` the two ops run as one fused epilogue
     (norm recomputed per vocab tile in VMEM — see
-    repro.kernels.emit_norm_logits); bitwise equal to the XLA path.
+    repro.kernels.emit_norm_logits); in bf16 bitwise equal to the XLA
+    path under the interpreter.
     """
     if kernels == "pallas":
         w = (
@@ -504,8 +505,8 @@ def decode_step(
 
     ``kernels`` (None inherits ``cfg.kernels``) selects the per-op
     implementations (see repro.kernels): ``"pallas"`` runs the fused
-    decode-attention and emit-epilogue kernels (bitwise equal to the
-    XLA path; interpret-emulated off-TPU)."""
+    decode-attention and emit-epilogue kernels (equal to the XLA path
+    to fp32 rounding; interpret-emulated off-TPU)."""
     plans = block_plans(cfg)
     mode = resolve_mode(cfg.kernels if kernels is None else kernels)
     if cfg.embeds_input:

@@ -17,7 +17,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models import params as P_
 
 PyTree = Any
@@ -88,8 +87,7 @@ def prune_spec(spec: P, mesh: Mesh) -> P:
             parts.append(None)
         elif isinstance(part, tuple):
             kept = tuple(a for a in part if a in mesh.axis_names)
-            # normalize singleton tuples: modern PartitionSpec does this
-            # internally, 0.4.x does not — keep both spellings equal
+            # normalize singleton tuples so equal specs compare equal
             parts.append(
                 None if not kept else (kept[0] if len(kept) == 1 else kept)
             )
@@ -160,10 +158,10 @@ def maybe_constrain(x, spec: P):
     import os
     if os.environ.get("REPRO_NO_CONSTRAIN") == "1":
         return x
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
-    manual = compat.manual_axis_names(mesh)
+    manual = frozenset(mesh.manual_axes)
     if manual:
         parts = []
         for part in spec:

@@ -352,6 +352,16 @@ def fused_region_present(text: str, marker: str) -> bool:
     return False
 
 
+def tpu_kernel_present(text: str, marker: str) -> bool:
+    """True iff a compiled Mosaic kernel call (``tpu_custom_call``)
+    carries ``marker`` in its ``op_name`` metadata: the fused kernel
+    itself runs on the TPU, not an XLA emulation of it."""
+    return any(
+        'custom_call_target="tpu_custom_call"' in line and marker in line
+        for line in text.splitlines()
+    )
+
+
 def head_matmul_conditional_only(text: str, logits_width: int) -> bool:
     """True iff the module contains at least one logits-width matmul and
     every one of them is conditional-guarded (see

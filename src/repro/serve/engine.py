@@ -53,10 +53,6 @@ for round-level fault recovery):
   * **cancellation** — ``cancel(uid)`` retires a queued or in-flight
     request through the normal retirement machinery (its slot frees for
     the next admission; takes effect at the next step/round boundary).
-  * **degraded mode** — a ``kernels="pallas"`` StreamEngine whose fused
-    kernels fail to dispatch falls back to the bitwise-identical
-    ``"xla"`` path, recording a degradation event, instead of taking
-    the engine down.
   * **honest drain** — ``run_until_drained`` raises
     :class:`DrainTimeoutError` naming the undrained uids when
     ``max_steps`` expires with requests still in flight, instead of
@@ -66,7 +62,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
 from collections import deque
 from functools import partial
 from typing import Any
@@ -164,7 +159,7 @@ class _EngineBase:
         self.active: list[Request | None] = [None] * scfg.max_batch
         self.queue: deque[Request] = deque()
         self._uid = 0
-        # Lifecycle event log: degradations, load sheds, cancellations,
+        # Lifecycle event log: load sheds, cancellations,
         # expiries — host-side observability, never on the device path.
         self.events: list[dict] = []
         # logits_at is passed traced (not static) so every ragged-tail
@@ -581,37 +576,28 @@ class StreamEngine(_EngineBase):
             params, T.init_cache(cfg, scfg.max_batch, scfg.max_len),
             pcfg.num_cells,
         )
+        if mesh is not None:
+            # Place each cell's weights and cache shard on its stage once:
+            # left on the default device, the whole chain would sit on
+            # device 0 and be copied out to the stages every round.
+            stage = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec(pcfg.axis_name)
+            )
+            self.cell_consts, self.cell_states = jax.device_put(
+                (self.cell_consts, self.cell_states), stage
+            )
         # Kernel dispatch for the hot path: the pipeline knob overrides
         # the model knob; resolved once ("auto" -> backend) so cells and
-        # emit agree.
+        # emit agree.  A failing fused kernel raises: there is no silent
+        # fallback that would serve from a different path.
         self.kernels = resolve_mode(
             cfg.kernels if pcfg.kernels is None else pcfg.kernels
         )
-        self.degraded = False
-        if self.kernels == "pallas":
-            # Probe the fused-kernel dispatch up front: an import-level
-            # failure degrades here, before any request is accepted.
-            try:
-                from repro.kernels import get_impl
-
-                get_impl("decode_attention", "pallas")
-                get_impl("emit_norm_logits", "pallas")
-            except Exception as e:  # noqa: BLE001
-                self._degrade("kernel import failed", e)
         self._zero_single = T.init_cache(cfg, 1, scfg.max_len)
         self._embed = jax.jit(
             lambda toks: L.embed_lookup(params["embed"]["embedding"], toks)
         )
         self._by_uid: dict[int, Request] = {}
-        self._build_programs()
-
-    def _build_programs(self):
-        """(Re)build the decode cells, emit, and jitted round under the
-        current ``self.kernels`` mode.  Called at init and again by
-        ``_degrade`` — the round must be re-jitted, not just re-pointed,
-        since jit caches trace the old cell bodies."""
-        cfg, scfg, pcfg = self.cfg, self.scfg, self.pcfg
-        params = self.params
         self._cell_fn = T.make_decode_cell(
             cfg,
             num_cells=pcfg.num_cells,
@@ -648,25 +634,6 @@ class StreamEngine(_EngineBase):
         # per-call warning there.)
         donate = (1,) if jax.default_backend() != "cpu" else ()
         self._round = jax.jit(_round, donate_argnums=donate)
-
-    def _degrade(self, reason: str, exc: Exception):
-        """Fall back from the fused pallas path to the bitwise-identical
-        xla path.  Served tokens are unchanged (the xla refs are the
-        kernels' oracles); the event is logged, never swallowed."""
-        self.degraded = True
-        self.kernels = "xla"
-        self.events.append({
-            "event": "degraded", "from": "pallas", "to": "xla",
-            "reason": reason, "error": f"{type(exc).__name__}: {exc}",
-        })
-        warnings.warn(
-            f"StreamEngine degraded kernels=pallas -> xla ({reason}: "
-            f"{type(exc).__name__}: {exc}); serving continues bit-identically",
-            RuntimeWarning,
-            stacklevel=3,
-        )
-        if hasattr(self, "_round"):  # runtime degrade: rebuild the round
-            self._build_programs()
 
     @property
     def cache(self) -> PyTree:
@@ -813,24 +780,10 @@ class StreamEngine(_EngineBase):
         # The admission payload is read-only within a round, so it rides
         # const_state — it never enters the mutable carry, and nothing
         # needs dropping afterwards (const state is not returned).
-        try:
-            new_states, collected = self._round(
-                {**self.cell_consts, "adm": adm},
-                self.cell_states, init_items, overlay,
-            )
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except Exception as e:  # noqa: BLE001
-            if self.kernels != "pallas":
-                raise
-            # Fused-kernel dispatch failed at trace/compile time:
-            # degrade to the xla cells (bitwise-identical tokens) and
-            # replay the identical round inputs.
-            self._degrade("round dispatch failed", e)
-            new_states, collected = self._round(
-                {**self.cell_consts, "adm": adm},
-                self.cell_states, init_items, overlay,
-            )
+        new_states, collected = self._round(
+            {**self.cell_consts, "adm": adm},
+            self.cell_states, init_items, overlay,
+        )
         self.cell_states = new_states
         col = {
             k: np.asarray(collected[k])

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.train.elastic import (
     ElasticPlan,
     choose_elastic_plan,
@@ -135,9 +134,9 @@ def test_remesh_state_roundtrip():
     sc = smoke_config(get_config("olmo-1b"))
     layout = T.model_layout(sc)
     params = init_params(jax.random.PRNGKey(0), layout)
-    mesh = compat.make_mesh(
+    mesh = jax.make_mesh(
         (1, 1), ("data", "model"),
-        axis_types=(compat.AxisType.Auto,) * 2,
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )
     resharded = remesh_state(params, layout, SH.TRAIN_RULES, mesh)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(resharded)):

@@ -1,13 +1,14 @@
-"""Fused decode-path kernels vs pure-jnp oracles — bitwise, interpret mode.
+"""Fused decode-path kernels vs pure-jnp oracles, interpret mode.
 
 The serving hot path dispatches two fused Pallas ops (see
 ``repro.kernels``): ``decode_attention`` (KV row scatter + single-row
 attention read, no updated slab materialized in HBM) and
-``emit_norm_logits`` (final-norm + logits head).  Both are gated on
-*bitwise* equality with their pure-jnp refs — the refs are verbatim the
-unfused model ops — so ``kernels="pallas"`` serving is token-identical
-to ``kernels="xla"`` by construction.  Also covers the dispatch
-registry and the training-path rejection.
+``emit_norm_logits`` (final-norm + logits head).  The refs are verbatim
+the unfused model ops.  In bf16 the emit kernel and a single-block
+decode attention are bitwise equal to them; a decode attention that
+walks several cache blocks is held to one bf16 ulp (see
+``_assert_matches``).  Also covers the dispatch registry and the
+training-path rejection.
 """
 import jax
 import jax.numpy as jnp
@@ -27,14 +28,21 @@ def _bitwise(a, b):
     return bool((a == b).all())
 
 
-def _assert_matches(out, ref, dtype):
-    """bf16 (the serving dtype): bitwise — the fp32 intermediate math is
-    identical op for op and both paths round through the same bf16 cast.
-    fp32: a few ULPs — XLA's CPU gemm/softmax reduction blocking differs
-    between the batched ref einsum and the kernel's per-row einsum for
-    some shapes, so exact fp32 bit equality would be shape-dependent."""
-    if dtype == jnp.bfloat16:
+def _assert_matches(out, ref, dtype, *, blocks=1):
+    """bf16 (the serving dtype), one cache block: bitwise — the fp32
+    intermediates round through the same bf16 cast.  Several blocks: the
+    online softmax sums block by block and normalizes after the V
+    reduction, so the fp32 value moves by rounding and the bf16 output
+    by at most one ulp (2**-7 relative).  fp32: a few ULPs — XLA's CPU
+    gemm/softmax reduction blocking differs between the batched ref and
+    the kernel's per-row math for some shapes, so exact fp32 bit
+    equality would be shape-dependent."""
+    if dtype == jnp.bfloat16 and blocks == 1:
         assert _bitwise(out, ref)
+    elif dtype == jnp.bfloat16:
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            rtol=2**-7, atol=2**-7)
     else:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
@@ -51,34 +59,44 @@ def _decode_case(rng, b, s, h, kv, dh, dtype, pos):
     return q, k_new, v_new, k_cache, v_cache, pos, kv_len
 
 
+# (dtype, block_s): block_s None is one block over the whole cache; a
+# smaller block_s walks several blocks (the max_len 2048 serving shape).
+BLOCK_CASES = [
+    (jnp.float32, None), (jnp.bfloat16, None),
+    (jnp.float32, 4), (jnp.bfloat16, 4),
+]
+
+
 class TestDecodeAttentionKernel:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
-    def test_ragged_positions_bitwise(self, dtype):
+    @pytest.mark.parametrize("dtype,block_s", BLOCK_CASES, ids=str)
+    def test_ragged_positions_bitwise(self, dtype, block_s):
         """Every row at a different depth — the steady decode tick."""
         rng = np.random.default_rng(0)
         b, s, h, kv, dh = 4, 16, 4, 2, 16
         pos = np.array([0, 5, 11, 15])  # includes fresh row and boundary
         q, kn, vn, kc, vc, pos, kvl = _decode_case(rng, b, s, h, kv, dh, dtype, pos)
         out = fused_decode_attention(
-            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True)
+            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True,
+            **({} if block_s is None else {"block_s": block_s}))
         ref = decode_attention_ref(q, kn, vn, kc, vc, pos=pos, kv_len=kvl)
         assert out.shape == ref.shape == (b, 1, h, dh)
-        _assert_matches(out, ref, dtype)
+        _assert_matches(out, ref, dtype, blocks=s // (block_s or s))
 
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
-    def test_max_len_boundary(self, dtype):
+    @pytest.mark.parametrize("dtype,block_s", BLOCK_CASES, ids=str)
+    def test_max_len_boundary(self, dtype, block_s):
         """All rows writing the last cache slot (pos == max_len - 1)."""
         rng = np.random.default_rng(1)
         b, s, h, kv, dh = 3, 8, 2, 2, 8
         q, kn, vn, kc, vc, pos, kvl = _decode_case(
             rng, b, s, h, kv, dh, dtype, np.full(3, s - 1))
         out = fused_decode_attention(
-            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True)
+            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True,
+            **({} if block_s is None else {"block_s": block_s}))
         ref = decode_attention_ref(q, kn, vn, kc, vc, pos=pos, kv_len=kvl)
-        _assert_matches(out, ref, dtype)
+        _assert_matches(out, ref, dtype, blocks=s // (block_s or s))
 
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
-    def test_admission_rows(self, dtype):
+    @pytest.mark.parametrize("dtype,block_s", BLOCK_CASES, ids=str)
+    def test_admission_rows(self, dtype, block_s):
         """Mid-round admissions: freshly prefilled rows (pos=0, garbage
         cache beyond the valid prefix) mixed with deep rows — the mask
         must come from kv_len, never from cache contents."""
@@ -90,9 +108,10 @@ class TestDecodeAttentionKernel:
         kc = kc.at[0, 1:].set(jnp.asarray(1e4, dtype))
         vc = vc.at[0, 1:].set(jnp.asarray(1e4, dtype))
         out = fused_decode_attention(
-            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True)
+            q, kn, vn, kc, vc, pos=pos, kv_len=kvl, interpret=True,
+            **({} if block_s is None else {"block_s": block_s}))
         ref = decode_attention_ref(q, kn, vn, kc, vc, pos=pos, kv_len=kvl)
-        _assert_matches(out, ref, dtype)
+        _assert_matches(out, ref, dtype, blocks=s // (block_s or s))
         assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
     def test_under_jit_matches_eager_ref(self):
@@ -134,13 +153,16 @@ class TestEmitNormLogitsKernel:
             x, w, norm=norm, scale=scale, tied=tied, interpret=True)
         ref = emit_norm_logits_ref(x, w, norm=norm, scale=scale, tied=tied)
         assert out.dtype == jnp.float32 and out.shape == (b, v)
-        assert _bitwise(out, ref)
+        # bf16 bitwise; fp32 to a few ULPs: with the kernel's explicit
+        # fp32 accumulation XLA's CPU backend fuses the rmsnorm scale into
+        # the interpreted fp32 dot differently from the unfused einsum.
+        _assert_matches(out, ref, dtype)
 
     def test_bitwise_vs_jitted_ref_bf16(self):
         """The hard case: under jit, XLA elides the f32->bf16->f32
         round-trip only for directly-chained dot->convert.  The kernel
-        keeps the dot in input dtype and upcasts outside the pallas
-        call, so it matches the ref both eager and jitted."""
+        stores its output block in the input dtype and upcasts outside
+        the pallas call, so it matches the ref both eager and jitted."""
         rng = np.random.default_rng(5)
         b, d, v = 2, 64, 128
         x = jnp.asarray(rng.normal(size=(b, 1, d)), jnp.bfloat16)

@@ -19,9 +19,12 @@ pytestmark = pytest.mark.multidevice
 
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+# Strict bf16 rounding at every op: XLA otherwise keeps fused
+# intermediates in fp32 where its fusion decisions allow, and those
+# differ between the pipelined and the sequential program.
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4"
+                           " --xla_allow_excess_precision=false")
 import jax, numpy as np
-from repro import compat
 from repro.configs.base import DecodePipelineConfig
 from repro.configs.registry import get_config, smoke_config
 from repro.models import transformer as T
@@ -30,7 +33,7 @@ from repro.serve.engine import Engine, ServeConfig, StreamEngine
 
 sc = smoke_config(get_config("olmo-1b")).with_overrides(num_layers=8)
 params = init_params(jax.random.PRNGKey(0), T.model_layout(sc))
-mesh = compat.make_mesh((4,), ("pod",), axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((4,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 
 scfg = ServeConfig(max_batch=8, max_len=64, prefill_chunk=4, max_new_tokens=6)
 rng = np.random.default_rng(7)

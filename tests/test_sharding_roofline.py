@@ -57,8 +57,7 @@ class TestFitSpec:
     def test_tuple_axis_partial_drop(self):
         # 32 % (2*16) == 0 keeps both; 16 % 32 != 0 drops from the right
         assert SH.fit_spec(P(("pod", "data")), (32,), MESH3) == P(("pod", "data"))
-        # normalized singleton: P("pod"), not P(("pod",)) (equal on modern
-        # JAX, distinct objects on 0.4.x)
+        # normalized singleton: P("pod"), not P(("pod",))
         assert SH.fit_spec(P(("pod", "data")), (2,), MESH3) == P("pod")
 
     def test_prune_removes_missing_axes(self):

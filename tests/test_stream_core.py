@@ -613,9 +613,7 @@ class TestPlannedBackwardValidation:
     shapes it cannot transpose (checked before any device work)."""
 
     def _mesh(self):
-        from repro import compat
-
-        return compat.make_mesh(
+        return jax.make_mesh(
             (1,), ("pod",), devices=jax.devices()[:1]
         )
 
