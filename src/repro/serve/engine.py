@@ -57,6 +57,39 @@ for round-level fault recovery):
     :class:`DrainTimeoutError` naming the undrained uids when
     ``max_steps`` expires with requests still in flight, instead of
     silently truncating.
+
+Observability.  ``engine.counters`` (:class:`EngineCounters`) holds
+cumulative plain ints, each incremented where the work happens:
+
+  * ``rounds`` — device rounds run (``Engine``: decode steps); over a
+    wall-clock interval, the round rate.
+  * ``tokens`` — decode tokens appended to requests (each request's
+    first token, sampled at prefill, is counted by ``prefills``).
+  * ``slot_steps`` — decode slot-steps computed: ``max_batch`` ×
+    ``round_steps`` per round.  ``tokens / slot_steps`` is the share of
+    the batch doing useful work; a low share with a queue means
+    admission lags retirement.
+  * ``prefills`` — requests prefilled; against submits, the backlog.
+  * ``shed`` — submits refused by ``max_queue``: load beyond capacity.
+  * ``cancelled`` — requests resolved by ``cancel``.
+  * ``expired`` — requests resolved by their deadline: work the
+    clients gave up on.
+
+A supervisor's replay of a faulted round counts the replayed work
+again: the counters count work done, not work delivered.
+
+Host spans (``jax.profiler.TraceAnnotation``) mark the same boundaries;
+they cost about a microsecond each with no profiler session open, and
+with one open they land in the profiler's trace on the device clock.
+``StreamEngine.step`` is ``serve.step`` (a step annotation, ``step_num``
+the round count; metadata ``tokens`` and ``slot_steps``, that round's
+counter deltas), holding ``serve.admit`` (deadlines and admission
+planning, with one ``serve.prefill`` per admitted request: ``uid``,
+``prompt_len``, ``queue_ms`` from submit to prefill), ``serve.inputs``
+(the round's inputs), ``serve.dispatch`` (the round's call),
+``serve.wait`` (the host blocked reading the round back) and
+``serve.walk`` (the token walk and the host slot sync).  ``Engine``
+opens ``serve.prefill`` only.
 """
 from __future__ import annotations
 
@@ -69,6 +102,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ArchConfig, DecodePipelineConfig
 from repro.core import FutureEvaluator, LazyEvaluator, Stream
@@ -117,6 +151,21 @@ class Request:
     done: bool = False
     deadline: float | None = None  # absolute time.monotonic() budget
     status: str = "ok"  # "ok" | "cancelled" | "expired"
+    submitted: float = dataclasses.field(default_factory=time.monotonic)
+
+
+@dataclasses.dataclass
+class EngineCounters:
+    """Cumulative counts of the engine's work; the module docstring
+    names each and what an operator reads from it."""
+
+    rounds: int = 0
+    tokens: int = 0
+    slot_steps: int = 0
+    prefills: int = 0
+    shed: int = 0
+    cancelled: int = 0
+    expired: int = 0
 
 
 def sample_token(logits, temperature: float, seed: int, uid, ngen):
@@ -159,9 +208,7 @@ class _EngineBase:
         self.active: list[Request | None] = [None] * scfg.max_batch
         self.queue: deque[Request] = deque()
         self._uid = 0
-        # Lifecycle event log: load sheds, cancellations,
-        # expiries — host-side observability, never on the device path.
-        self.events: list[dict] = []
+        self.counters = EngineCounters()
         # logits_at is passed traced (not static) so every ragged-tail
         # length shares one compiled prefill per chunk width.
         self._prefill = jax.jit(
@@ -194,7 +241,7 @@ class _EngineBase:
             )
         mq = self.scfg.max_queue
         if mq is not None and len(self.queue) >= mq:
-            self.events.append({"event": "load_shed", "queue": len(self.queue)})
+            self.counters.shed += 1
             raise QueueFullError(
                 f"admission queue full ({len(self.queue)} >= max_queue={mq})"
             )
@@ -225,13 +272,13 @@ class _EngineBase:
             if req.uid == uid and not req.done:
                 self.queue.remove(req)
                 req.done, req.status = True, "cancelled"
-                self.events.append({"event": "cancel", "uid": uid})
+                self.counters.cancelled += 1
                 return True
         for slot, req in enumerate(self.active):
             if req is not None and req.uid == uid and not req.done:
                 req.done, req.status = True, "cancelled"
                 self._retire_slot(slot)
-                self.events.append({"event": "cancel", "uid": uid})
+                self.counters.cancelled += 1
                 return True
         return False
 
@@ -279,10 +326,7 @@ class _EngineBase:
                 req.done, req.status = True, "expired"
                 self._retire_slot(slot)
                 expired.append(req)
-        if expired:
-            self.events.append(
-                {"event": "expired", "uids": [r.uid for r in expired]}
-            )
+        self.counters.expired += len(expired)
         return expired
 
     def _sample_host(self, logits_row: np.ndarray, uid: int, ngen: int) -> int:
@@ -312,28 +356,33 @@ class _EngineBase:
         ck = self.scfg.prefill_chunk
         prompt = req.prompt
         plen = len(prompt)
-        full = (plen // ck) * ck
-        single = T.init_cache(self.cfg, 1, self.scfg.max_len)
-        logits = None
-        for c in range(full // ck):
-            chunk = jnp.asarray(prompt[None, c * ck : (c + 1) * ck])
-            logits, single = self._prefill(
-                self.params, single, tokens=chunk, pos=c * ck
-            )
-        rem = plen - full
-        if rem:
-            # Pad the tail to one masked chunk — clamped to the cache
-            # end so the write can never clamp-and-corrupt earlier rows
-            # when max_len is not a multiple of the chunk size.
-            width = min(ck, self.scfg.max_len - full)
-            tail = np.zeros((1, width), np.int32)
-            tail[0, :rem] = prompt[full:]
-            logits, single = self._prefill(
-                self.params, single,
-                tokens=jnp.asarray(tail), pos=full,
-                logits_at=jnp.asarray(rem - 1, jnp.int32),
-            )
-        tok = self._sample_host(np.asarray(logits)[0], req.uid, 0)
+        self.counters.prefills += 1
+        with TraceAnnotation(
+            "serve.prefill", uid=req.uid, prompt_len=plen,
+            queue_ms=1e3 * (time.monotonic() - req.submitted),
+        ):
+            full = (plen // ck) * ck
+            single = T.init_cache(self.cfg, 1, self.scfg.max_len)
+            logits = None
+            for c in range(full // ck):
+                chunk = jnp.asarray(prompt[None, c * ck : (c + 1) * ck])
+                logits, single = self._prefill(
+                    self.params, single, tokens=chunk, pos=c * ck
+                )
+            rem = plen - full
+            if rem:
+                # Pad the tail to one masked chunk — clamped to the cache
+                # end so the write can never clamp-and-corrupt earlier rows
+                # when max_len is not a multiple of the chunk size.
+                width = min(ck, self.scfg.max_len - full)
+                tail = np.zeros((1, width), np.int32)
+                tail[0, :rem] = prompt[full:]
+                logits, single = self._prefill(
+                    self.params, single,
+                    tokens=jnp.asarray(tail), pos=full,
+                    logits_at=jnp.asarray(rem - 1, jnp.int32),
+                )
+            tok = self._sample_host(np.asarray(logits)[0], req.uid, 0)
         req.out_tokens.append(tok)
         done = (
             len(req.out_tokens) >= req.max_new_tokens
@@ -392,6 +441,9 @@ class Engine(_EngineBase):
             tokens=jnp.asarray(tokens),
             lengths=jnp.asarray(self.lengths),
         )
+        self.counters.rounds += 1
+        self.counters.slot_steps += self.scfg.max_batch
+        self.counters.tokens += len(slots)
         logits = np.asarray(logits)
         if self.scfg.temperature > 0:
             # One batched draw for all active slots (the same vmapped
@@ -594,9 +646,12 @@ class StreamEngine(_EngineBase):
             cfg.kernels if pcfg.kernels is None else pcfg.kernels
         )
         self._zero_single = T.init_cache(cfg, 1, scfg.max_len)
-        self._embed = jax.jit(
-            lambda toks: L.embed_lookup(params["embed"]["embedding"], toks)
-        )
+
+        # A named function, so that traces call the program by its name.
+        def embed_tokens(toks):
+            return L.embed_lookup(params["embed"]["embedding"], toks)
+
+        self._embed = jax.jit(embed_tokens)
         self._by_uid: dict[int, Request] = {}
         self._cell_fn = T.make_decode_cell(
             cfg,
@@ -766,29 +821,55 @@ class StreamEngine(_EngineBase):
 
     def step(self) -> list[Request]:
         """One pipelined round of ``round_steps`` decode steps."""
-        t_, m_ = self.pcfg.round_steps, self.pcfg.microbatches
-        bm = self.mb_size
-        finished = self._expire_deadlines()
-        admissions, planned = self._plan_admissions(t_)
-        finished.extend(planned)
-        for slot, req in enumerate(self.active):
-            if req is not None:
-                self._by_uid[req.uid] = req
+        c = self.counters
+        tokens, slot_steps = c.tokens, c.slot_steps
+        with StepTraceAnnotation("serve.step", step_num=c.rounds) as span:
+            finished = self._step()
+            span.set_metadata(
+                tokens=c.tokens - tokens, slot_steps=c.slot_steps - slot_steps
+            )
+        return finished
+
+    def _step(self) -> list[Request]:
+        t_ = self.pcfg.round_steps
+        with TraceAnnotation("serve.admit"):
+            finished = self._expire_deadlines()
+            admissions, planned = self._plan_admissions(t_)
+            finished.extend(planned)
+            for slot, req in enumerate(self.active):
+                if req is not None:
+                    self._by_uid[req.uid] = req
         if not admissions and all(r is None for r in self.active):
             return finished
-        init_items, overlay, adm = self._build_round_inputs(admissions)
+        with TraceAnnotation("serve.inputs"):
+            init_items, overlay, adm = self._build_round_inputs(admissions)
         # The admission payload is read-only within a round, so it rides
         # const_state — it never enters the mutable carry, and nothing
         # needs dropping afterwards (const state is not returned).
-        new_states, collected = self._round(
-            {**self.cell_consts, "adm": adm},
-            self.cell_states, init_items, overlay,
-        )
+        with TraceAnnotation("serve.dispatch"):
+            new_states, collected = self._round(
+                {**self.cell_consts, "adm": adm},
+                self.cell_states, init_items, overlay,
+            )
         self.cell_states = new_states
-        col = {
-            k: np.asarray(collected[k])
-            for k in ("tok", "pos", "active", "uid", "ngen")
-        }
+        self.counters.rounds += 1
+        self.counters.slot_steps += t_ * self.scfg.max_batch
+        with TraceAnnotation("serve.wait"):
+            col = {
+                k: np.asarray(collected[k])
+                for k in ("tok", "pos", "active", "uid", "ngen")
+            }
+        with TraceAnnotation("serve.walk"):
+            finished.extend(self._walk(col))
+        return finished
+
+    def _walk(self, col: dict[str, np.ndarray]) -> list[Request]:
+        """Hand the round's tokens to their requests, then sync the host's
+        slot state; returns the requests that finished."""
+        t_, m_ = self.pcfg.round_steps, self.pcfg.microbatches
+        bm = self.mb_size
+        finished = []
+        appended = 0
         # Walk emitted items in stream order; a row's token is real when
         # its ngen is one past what the host has — frozen (retired) rows
         # repeat their ngen and are skipped, exactly mirroring the emit.
@@ -802,6 +883,7 @@ class StreamEngine(_EngineBase):
                     continue
                 tok = int(col["tok"][b, r])
                 req.out_tokens.append(tok)
+                appended += 1
                 done = (
                     g >= req.max_new_tokens
                     or tok == self.scfg.eos_id
@@ -810,6 +892,7 @@ class StreamEngine(_EngineBase):
                 if done:
                     req.done = True
                     finished.append(req)
+        self.counters.tokens += appended
         # Host slot state syncs from each microbatch's final item.
         for mb in range(m_):
             b = (t_ - 1) * m_ + mb

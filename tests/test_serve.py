@@ -232,6 +232,14 @@ class TestStreamEngineLazy:
         for ra, rb in zip(reqs_a, reqs_b):
             assert rb.done
             assert ra.out_tokens == rb.out_tokens
+        # Both engines count the same work: one prefill and its first
+        # token per request, every later token in a decode slot-step.
+        decoded = sum(len(r.out_tokens) - 1 for r in reqs_b)
+        for e, per_round in ((ref, scfg.max_batch),
+                             (eng, round_steps * scfg.max_batch)):
+            c = e.counters
+            assert c.prefills == len(prompts) and c.tokens == decoded
+            assert c.slot_steps == c.rounds * per_round >= decoded
 
     def test_temperature_matches_sequential(self, cell_model):
         sc, params = cell_model

@@ -305,7 +305,7 @@ class TestRequestLifecycle:
         eng.submit(np.array([3, 4]))
         with pytest.raises(QueueFullError):
             eng.submit(np.array([5, 6]))
-        assert {"event": "load_shed", "queue": 2} in eng.events
+        assert eng.counters.shed == 1
         assert len(eng.queue) == 2  # the shed request was never accepted
 
     def test_deadline_expires_queued_request(self, cell_model, seq_rig):
@@ -317,6 +317,7 @@ class TestRequestLifecycle:
         done = eng.run_until_drained()
         assert dead.done and dead.status == "expired" and dead in done
         assert dead.out_tokens == []
+        assert eng.counters.expired == 1
         # survivors are untouched by the expiry
         assert [r.out_tokens for r in keep] == golden
         assert all(r.status == "ok" for r in keep)
@@ -332,6 +333,7 @@ class TestRequestLifecycle:
         done = eng.step()
         assert req in done and req.status == "expired"
         assert len(req.out_tokens) > 0  # partial output is kept
+        assert eng.counters.expired == 1
         assert all(r is not req for r in eng.active)
 
     def test_cancel_queued_and_active(self, cell_model):
@@ -346,6 +348,7 @@ class TestRequestLifecycle:
         assert not eng.cancel(9999)    # unknown uid
         assert ra.status == rq.status == "cancelled"
         assert ra.done and rq.done
+        assert eng.counters.cancelled == 2
         # the freed slot is reusable
         rest = eng.submit(np.array([2, 2]))
         eng.run_until_drained()
