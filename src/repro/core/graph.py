@@ -523,11 +523,15 @@ def _const_cell(cell_fn: CellFn, has_const: bool) -> CellFn:
 
 
 def scan_cell(cell_fn: CellFn, mutable: bool):
-    """The one cell-loop scan body every executor uses: carry = the
-    flowing item, xs = ``(const_row, state_row)``, ys = the (possibly
-    frozen) new state row.  A single definition site — Lazy ≡ Future
-    bit-equality rests on the per-cell primitive sequence being
-    identical, so the wrapper must never fork per executor."""
+    """The one cell body every executor applies: ``(flowing, (const_row,
+    state_row)) -> (flowing', state_row')``, the new state row frozen to
+    the old when the segment is immutable.  Its shape is a ``lax.scan``
+    body (carry = the flowing item, xs = the rows, ys = the new row),
+    and the scanning executors use it so; the Lazy feedback executor
+    (:func:`run_chain_sequential`) calls it once per cell in a static
+    loop, on the cell's own state buffer.  A single definition site —
+    Lazy ≡ Future bit-equality rests on the per-cell primitive sequence
+    being identical, so the wrapper must never fork per executor."""
 
     def cell(flowing, xs):
         cst, state = xs
@@ -1084,15 +1088,31 @@ def _chain_cell_machinery(chain: "ChainProgram"):
     )
 
 
+def _cell_row(tree: PyTree, c: int) -> PyTree:
+    """Cell ``c``'s row of a ``(num_cells, ...)``-stacked pytree."""
+    return jax.tree.map(lambda l: lax.index_in_dim(l, c, keepdims=False), tree)
+
+
 def run_chain_sequential(chain: "ChainProgram") -> tuple[tuple, PyTree]:
     """Execute a lowered :class:`ChainProgram` item-by-item on one device.
 
     The Lazy monad over the *lowered* form: one ``lax.scan`` over items,
-    cells advanced by inner scans split only at interior injection
-    boundaries.  This is the executor that runs feedback chains
-    sequentially (``lazy_eval_graph`` cannot — feedback has no node-local
-    order): the carry holds a ``lag``-deep FIFO of pending inputs, and
-    each emitted item is both collected and pushed onto the FIFO's tail.
+    and within an item the cells applied in turn by a static loop, with
+    interior injections merged at their cell.  This is the executor that
+    runs feedback chains sequentially (``lazy_eval_graph`` cannot —
+    feedback has no node-local order): the carry holds a ``lag``-deep
+    FIFO of pending inputs, and each emitted item is both collected and
+    pushed onto the FIFO's tail.
+
+    Each cell's mutable state is its own buffer in the item scan's
+    carry: the stacked ``(num_cells, ...)`` state is split once before
+    the scan and restacked once after it, and cell ``c`` reads and
+    replaces only buffer ``c`` (its const row is a static slice).  A
+    cell that writes a few rows of a large state (a KV cache) therefore
+    updates it in place; threading the stack through a cell scan's xs
+    and ys would slice out, relay and write back every cell's whole
+    state at every item.  The cost is one cell body per cell in the
+    program.
 
     Returns ``(segment_states, out_items)`` like the Future engine.
     """
@@ -1102,6 +1122,7 @@ def run_chain_sequential(chain: "ChainProgram") -> tuple[tuple, PyTree]:
     cell_fn, init_state, const_state, mutable, split_states = (
         _chain_cell_machinery(chain)
     )
+    cell = scan_cell(cell_fn, mutable)
 
     entry = [
         i for i, inj in enumerate(chain.injections)
@@ -1115,32 +1136,23 @@ def run_chain_sequential(chain: "ChainProgram") -> tuple[tuple, PyTree]:
         i for i, inj in enumerate(chain.injections)
         if i > 0 and chain.num_cells > 0 and inj.cell_index >= chain.num_cells
     ]
-    boundaries = sorted({chain.injections[i].cell_index for i in interior})
-    spans = list(
-        zip([0] + boundaries, boundaries + [chain.num_cells])
-    ) if chain.num_cells else []
+    cells0 = tuple(_cell_row(init_state, c) for c in range(chain.num_cells))
 
-    def run_item(states, flow, src_items):
+    def restack(cells):
+        if not cells:
+            return init_state
+        return jax.tree.map(lambda *ls: jnp.stack(ls), *cells)
+
+    def run_item(cells, flow, src_items):
         for i in entry:
             flow = chain.injections[i].combine(flow, src_items[str(i)])
-        parts = []
-        for a, b in spans:
+        cells = list(cells)
+        for c in range(chain.num_cells):
             for i in interior:
-                if chain.injections[i].cell_index == a:
+                if chain.injections[i].cell_index == c:
                     flow = chain.injections[i].combine(flow, src_items[str(i)])
-            sub = jax.tree.map(lambda l: l[a:b], states)
-            sub_const = jax.tree.map(lambda l: l[a:b], const_state)
-            flow, new_sub = lax.scan(
-                scan_cell(cell_fn, mutable), flow, (sub_const, sub)
-            )
-            parts.append(new_sub)
-        if not parts:
-            return states, flow
-        if len(parts) == 1:
-            return parts[0], flow
-        return jax.tree.map(
-            lambda *ps: jnp.concatenate(ps, axis=0), *parts
-        ), flow
+            flow, cells[c] = cell(flow, (_cell_row(const_state, c), cells[c]))
+        return tuple(cells), flow
 
     src_xs = {
         str(i): feeds[i] for i in entry + interior
@@ -1155,29 +1167,28 @@ def run_chain_sequential(chain: "ChainProgram") -> tuple[tuple, PyTree]:
         _check_emit_structure(fb.emit, flow0)
 
         def step(carry, xs):
-            states, ring = carry
+            cells, ring = carry
             flow = jax.tree.map(lambda r: r[0], ring)
-            new_states, out = run_item(states, flow, xs)
+            cells, out = run_item(cells, flow, xs)
             emitted = fb.emit(out)
             ring = jax.tree.map(
                 lambda r, e: jnp.concatenate([r[1:], e[None]], axis=0),
                 ring,
                 emitted,
             )
-            return (new_states, ring), emitted
+            return (cells, ring), emitted
 
-        (final_states, _), outs = lax.scan(
-            step, (init_state, feeds[0]), src_xs, length=n
+        (final_cells, _), outs = lax.scan(
+            step, (cells0, feeds[0]), src_xs, length=n
         )
-        return split_states(final_states), outs
+        return split_states(restack(final_cells)), outs
 
-    def step(carry, xs):
-        new_states, out = run_item(carry, xs["__primary__"], xs)
-        return new_states, out
+    def step(cells, xs):
+        return run_item(cells, xs["__primary__"], xs)
 
     xs = dict(src_xs)
     xs["__primary__"] = feeds[0]
-    final_states, outs = lax.scan(step, init_state, xs, length=n)
+    final_cells, outs = lax.scan(step, cells0, xs, length=n)
     for i in tail:
         outs = apply_per_item(
             lambda ab, _c=chain.injections[i].combine: _c(*ab),
@@ -1185,4 +1196,4 @@ def run_chain_sequential(chain: "ChainProgram") -> tuple[tuple, PyTree]:
         )
     if chain.finalize is not None:
         outs = apply_per_item(chain.finalize, outs)
-    return split_states(final_states), outs
+    return split_states(restack(final_cells)), outs

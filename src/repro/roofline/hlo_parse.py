@@ -276,6 +276,33 @@ def _call_edges(instrs):
                     yield name, guarded
 
 
+def _unguarded_reach(comps, entry) -> set[tuple[str, bool]]:
+    """``(computation, inside a loop)`` for every computation reachable
+    from the entry without crossing into a conditional branch; inside a
+    loop once the path has entered a ``while`` body or condition."""
+    edges = {name: list(_call_edges(instrs)) for name, instrs in comps.items()}
+    loop_edges = {
+        name: {
+            m.group(1)
+            for ins in instrs
+            if ins.opcode == "while"
+            for m in re.finditer(r"(?:body|condition)=%?([\w.\-]+)", ins.attrs)
+        }
+        for name, instrs in comps.items()
+    }
+    seen: set[tuple[str, bool]] = set()
+    frontier = [(entry, False)] if entry else []
+    while frontier:
+        name, in_loop = frontier.pop()
+        if (name, in_loop) in seen or name not in comps:
+            continue
+        seen.add((name, in_loop))
+        for callee, guarded in edges[name]:
+            if not guarded:
+                frontier.append((callee, in_loop or callee in loop_edges[name]))
+    return seen
+
+
 def unguarded_matches(text: str, match) -> tuple[int, int]:
     """Count instructions satisfying ``match(Instr)`` in the module, and
     how many of those sit in a computation reachable from the entry
@@ -287,20 +314,7 @@ def unguarded_matches(text: str, match) -> tuple[int, int]:
     device's executed tick body never contains the op.
     """
     comps, entry = parse_module(text)
-    edges: dict[str, list[tuple[str, bool]]] = {}
-    for name, instrs in comps.items():
-        edges[name] = list(_call_edges(instrs))
-    # BFS over non-guarded edges only.
-    unguarded_comps: set[str] = set()
-    frontier = [entry] if entry else []
-    while frontier:
-        name = frontier.pop()
-        if name in unguarded_comps or name not in comps:
-            continue
-        unguarded_comps.add(name)
-        for callee, guarded in edges.get(name, ()):
-            if not guarded:
-                frontier.append(callee)
+    unguarded_comps = {name for name, _ in _unguarded_reach(comps, entry)}
     total = unguarded = 0
     for name, instrs in comps.items():
         for ins in instrs:
@@ -310,6 +324,38 @@ def unguarded_matches(text: str, match) -> tuple[int, int]:
             if name in unguarded_comps:
                 unguarded += 1
     return total, unguarded
+
+
+def loop_state_copies(text: str, min_bytes: int) -> list[tuple[str, Instr]]:
+    """Whole-state moves inside loops: ``(computation, instr)`` for every
+    ``copy`` or ``dynamic-slice`` whose output, and every
+    ``dynamic-update-slice`` whose update, is at least ``min_bytes``, in
+    a computation that runs in a ``while`` body (or condition) without
+    crossing into a conditional branch.
+
+    A loop whose carried state is updated in place (row scatters, a
+    small ``dynamic-update-slice`` into a large buffer) has none; one
+    that slices a state out of a stack, relays it or writes it back
+    whole has one per move, repeated every iteration.  Ops in the entry
+    computation run once per call and are not counted; ops behind a
+    conditional run only when its predicate fires.
+    """
+    comps, entry = parse_module(text)
+    seen = _unguarded_reach(comps, entry)
+    found = []
+    for name in sorted({n for n, in_loop in seen if in_loop}):
+        instrs = comps[name]
+        out_bytes = {i.name: i.out_bytes for i in instrs}
+        for ins in instrs:
+            if ins.opcode in ("copy", "dynamic-slice"):
+                moved = ins.out_bytes
+            elif ins.opcode == "dynamic-update-slice" and len(ins.operands) > 1:
+                moved = out_bytes.get(ins.operands[1], 0)
+            else:
+                continue
+            if moved >= min_bytes:
+                found.append((name, ins))
+    return found
 
 
 def slab_scatter_counts(text: str, slab_bytes: int) -> tuple[int, int]:

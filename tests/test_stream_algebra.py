@@ -448,6 +448,96 @@ class TestFeedback:
         assert int((p.microbatch >= 0).sum()) == 4 * 16
 
 
+class TestPerCellState:
+    """``run_chain_sequential`` (Lazy feedback chains) keeps each cell's
+    mutable state in its own loop buffer and applies the cells by a
+    static loop: outputs and final states equal a hand-rolled loop over
+    items and cells, for one cell, a few and many, and for a chain whose
+    interior injection splits it into two segments (the unified
+    multi-segment state).  Integer arithmetic, so equal means bitwise."""
+
+    @staticmethod
+    def _cell(const, state, x):
+        acc = (state["acc"] * 5 + x * const + state["n"]) % 1009
+        return {"acc": acc, "n": state["n"] + 1}, (x * 3 + acc) % 997
+
+    @staticmethod
+    def _emit(x):
+        return (x * 7 + 1) % 991
+
+    @staticmethod
+    def _combine(flow, src):
+        return (flow + src) % 983
+
+    @staticmethod
+    def _ints(shape, seed):
+        return jnp.asarray(
+            np.random.default_rng(seed).integers(0, 1000, size=shape),
+            jnp.int32,
+        )
+
+    def _segment(self, num_cells, seed):
+        state = {
+            "acc": self._ints((num_cells, 3), seed),
+            "n": jnp.arange(num_cells, dtype=jnp.int32) * (seed + 1),
+        }
+        return state, self._ints((num_cells, 1), seed + 100)
+
+    def _reference(self, init, n, segments, src):
+        lag = init.shape[0]
+        cells = [
+            (jax.tree.map(lambda l, _c=c: l[_c], state), const[c], k)
+            for k, (state, const) in enumerate(segments)
+            for c in range(const.shape[0])
+        ]
+        outs = []
+        for b in range(n):
+            flow = init[b] if b < lag else outs[b - lag]
+            for c, (state, const, k) in enumerate(cells):
+                if c > 0 and cells[c - 1][2] != k:
+                    flow = self._combine(flow, src[b])
+                new, flow = self._cell(const, state, flow)
+                cells[c] = (new, const, k)
+            outs.append(self._emit(flow))
+        finals = tuple(
+            jax.tree.map(
+                lambda *ls: jnp.stack(ls),
+                *[st for st, _, k in cells if k == seg],
+            )
+            for seg in range(len(segments))
+        )
+        return jnp.stack(outs), finals
+
+    @pytest.mark.parametrize(
+        "cells", [(1,), (3,), (8,), (3, 2)],
+        ids=["1_cell", "3_cells", "8_cells", "interior_zip_3_2"],
+    )
+    def test_matches_hand_rolled_loop(self, cells):
+        lag, n = 3, 11
+        init = self._ints((lag, 3), 5)
+        src = self._ints((n, 3), 6)
+        segments = [self._segment(c, seed=k) for k, c in enumerate(cells)]
+        stream = Stream.feedback(init, n, self._emit)
+        for k, (state, const) in enumerate(segments):
+            if k > 0:
+                stream = stream.zip(Stream.source(src), self._combine)
+            stream = stream.through(self._cell, state, const_state=const)
+        chain = stream.lower()
+        assert chain.num_cells == sum(cells)
+        assert [i.cell_index for i in chain.injections[1:]] == [
+            sum(cells[:k]) for k in range(1, len(cells))
+        ]
+        res = stream.collect(LazyEvaluator())
+        ref_items, ref_states = self._reference(init, n, segments, src)
+        np.testing.assert_array_equal(np.asarray(res.items), np.asarray(ref_items))
+        assert len(res.states) == len(cells)
+        for got, want in zip(res.states, ref_states):
+            for key in ("acc", "n"):
+                np.testing.assert_array_equal(
+                    np.asarray(got[key]), np.asarray(want[key])
+                )
+
+
 class TestLowering:
     def test_entry_zip_two_injections(self):
         x, y = _items(seed=1), _items(seed=2)
